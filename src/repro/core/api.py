@@ -45,6 +45,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+from repro.core import device
 from repro.core import islands as islands_mod
 from repro.core import qlang
 from repro.core.engines import ENGINES
@@ -313,12 +314,17 @@ def connect(state_path: Optional[str] = None, *,
     (where the telemetry registry persists; defaults to
     ``<root>.metrics.json`` beside the monitor DB)...
 
+    JAX's persistent compilation cache is turned on here
+    (``device.enable_compile_cache``): ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else ``.jax_cache/`` at the root of the checkout.
+
     ``processes=N`` backs the session with a ``core.procpool.ProcPool`` —
     N worker processes each running a full middleware stack, sharing plans
     and monitor history through the ``state_path`` files, with sharded
     scatter–gather execution for ``register(..., shards=)`` tables.  Close
     the session (or use it as a context manager) to stop the workers.
     """
+    device.enable_compile_cache()
     if processes is not None and processes > 1:
         if bigdawg is not None or monitor is not None:
             raise ValueError("processes= builds its own per-worker "

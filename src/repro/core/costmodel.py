@@ -439,21 +439,19 @@ class CostModel:
             rng.normal(size=(n, n)).astype(np.float32)))
         jax.block_until_ready(base.data)
 
-        # cast bandwidth per registered (src, dst) pair
+        # cast bandwidth per registered (src, dst) pair; every source kind
+        # is reachable from the dense sample, and a failing cast propagates
         homed = {"dense": base}
         for (src, dst) in list(castmod._CASTS):
-            try:
-                if src not in homed:
-                    homed[src] = castmod.cast(base, src)
-                obj = homed[src]
-                for _ in range(repeats):
-                    t0 = time.perf_counter()
-                    out = castmod.cast(obj, dst)
-                    jax.block_until_ready(jax.tree.leaves(out.__dict__))
-                    dt = time.perf_counter() - t0
-                self.observe_cast(src, dst, obj.nbytes, dt)
-            except Exception:
-                continue            # pair not reachable from a dense sample
+            if src not in homed:
+                homed[src] = castmod.cast(base, src)
+            obj = homed[src]
+            for _ in range(repeats):
+                t0 = time.perf_counter()
+                out = castmod.cast(obj, dst)
+                jax.block_until_ready(jax.tree.leaves(out.__dict__))
+                dt = time.perf_counter() - t0
+            self.observe_cast(src, dst, obj.nbytes, dt)
 
         # per-engine op throughput: cheap scans, the binary matmul (the
         # planner's dominant op), and the layout-sensitive transforms whose
@@ -473,26 +471,23 @@ class CostModel:
             "stream": [("window_agg", {"fn": "mean"}), ("to_array", {}),
                        ("haar", {"levels": 2})],
         }
+        # (an op the engine lacks is skipped; an engine or kernel failure
+        # propagates — a probe that hid it would leave the engine priced
+        # from defaults and the failure for the first query to find)
         for ename, ops in probe.items():
             eng = ENGINES[ename]
-            try:
-                inp = homed.get(eng.kind) or castmod.cast(base, eng.kind)
-            except Exception:
-                continue
+            inp = homed[eng.kind]
             for op, attrs in ops:
                 if not eng.supports(op):
                     continue
                 args = (inp, inp) if op == "matmul" else (inp,)
-                try:
-                    for _ in range(repeats):
-                        t0 = time.perf_counter()
-                        out = eng.run(op, attrs, *args)
-                        jax.block_until_ready(jax.tree.leaves(out.__dict__))
-                        dt = time.perf_counter() - t0
-                    elems = sum(container_elems(a) for a in args)
-                    self.observe_op(ename, op, elems, dt)
-                except Exception:
-                    continue
+                for _ in range(repeats):
+                    t0 = time.perf_counter()
+                    out = eng.run(op, attrs, *args)
+                    jax.block_until_ready(jax.tree.leaves(out.__dict__))
+                    dt = time.perf_counter() - t0
+                elems = sum(container_elems(a) for a in args)
+                self.observe_op(ename, op, elems, dt)
         self.calibrated = True
         self.save()
 

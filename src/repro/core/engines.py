@@ -86,7 +86,10 @@ def _da_distinct(attrs, d: DenseTensor):
 
 
 def _da_matmul(attrs, a: DenseTensor, b: DenseTensor):
-    return DenseTensor(jnp.dot(a.data, b.data))
+    # HIGHEST: the TPU's default precision would round f32 operands to
+    # bfloat16 — an approximate answer where every other engine is exact
+    return DenseTensor(jnp.dot(a.data, b.data,
+                               precision=jax.lax.Precision.HIGHEST))
 
 
 def _da_select(attrs, d: DenseTensor):

@@ -323,11 +323,12 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
     meaning for the monitor, drift re-planning and the straggler
     detectors).  ``health.before_op`` still fires per member op, so
     fault-injection and breakers see fused serves exactly like unfused
-    ones.  Any fused-call failure falls back to node-by-node execution of
+    ones.  A segment that cannot be traced as one program
+    (``fuseplan.FUSION_REFUSALS``) falls back to node-by-node execution of
     the members inside the same task (sticky per segment signature — see
-    ``fuseplan.mark_broken``), so fusion can never turn a servable query
-    into an error.  Sequential (training) mode ignores ``fused``: per-node
-    calibration timings must stay pure.
+    ``fuseplan.mark_broken``); every other failure propagates as it would
+    from the unfused ops.  Sequential (training) mode ignores ``fused``:
+    per-node calibration timings must stay pure.
 
     ``trace`` (a ``core.tracing.Span``, or None) attaches already-measured
     ``engine_op`` / ``fused_segment`` / ``cast`` child spans under the
@@ -417,9 +418,10 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
                         fused_ran.append(seg.positions)
                         if was_cold:
                             cold_compiles[0] += 1
-                    except Exception as exc:
-                        # trace/compile/run failure: never an error for the
-                        # caller — mark sticky, count, re-run unfused below
+                    except fuseplan.FUSION_REFUSALS as exc:
+                        # the chain cannot be traced as one program: mark
+                        # sticky, count, re-run unfused below.  Compile,
+                        # kernel and device failures propagate
                         fuseplan.mark_broken(seg.key, repr(exc))
                         fallbacks[0] += 1
                         out = None

@@ -36,14 +36,17 @@ leaves on the table.  This module closes it for the dense/array family:
       middleware stores the ``FusedPlan`` on its ``CachedPlan`` entry
       (runtime-only — never persisted, like the alternate-rotation cursor).
 
-  fallback  fusion must never change results or turn a servable query into
-      an error.  Any failure of the fused call — trace, compile, or run —
-      marks the segment key *broken* in a process-wide registry
-      (``mark_broken``) and the executor re-runs the members node-by-node
-      in the same host task; later serves see the sticky mark and skip the
-      fused attempt for that signature.  ``ExecutionResult.fusion_fallbacks``
-      counts transitions, and the middleware rolls them up into
-      ``stats["fusion_fallbacks"]``.
+  fallback  fusion must never change results.  A segment that cannot be
+      traced as one program (``FUSION_REFUSALS``: a member needs a concrete
+      value at trace time) marks the segment key *broken* in a process-wide
+      registry (``mark_broken``) and the executor re-runs the members
+      node-by-node in the same host task; later serves see the sticky mark
+      and skip the fused attempt for that signature.
+      ``ExecutionResult.fusion_fallbacks`` counts transitions, and the
+      middleware rolls them up into ``stats["fusion_fallbacks"]``.  Any
+      other failure — a compile error, a refused kernel, a device fault —
+      propagates like the same failure of an unfused op: re-running it
+      node by node would only hide it.
 
 Equivalence notes (what the ``tests/test_fusion.py`` property battery
 pins): member semantics mirror ``engines._da_*`` exactly — intermediates
@@ -93,6 +96,18 @@ FUSABLE_ENGINES = frozenset({"dense_array"})
 MIN_SEGMENT_NODES = 2
 
 # -- process-wide compiled-callable cache + sticky fallback registry --------
+class FusionRefused(Exception):
+    """A segment cannot be traced as one program.  Raised by fault
+    injection (``runtime.fault.FusionFaultInjector``); JAX's own tracer
+    errors below mean the same."""
+
+
+# the only failures the executor answers by running a segment node by node
+FUSION_REFUSALS = (FusionRefused, jax.errors.ConcretizationTypeError,
+                   jax.errors.TracerBoolConversionError,
+                   jax.errors.TracerIntegerConversionError,
+                   jax.errors.TracerArrayConversionError)
+
 _COMPILED: Dict[str, Callable] = {}
 _BROKEN: Dict[str, str] = {}        # segment key -> failure description
 _WARM: set = set()                  # (key, ext shapes/dtypes) runs completed
@@ -163,7 +178,7 @@ class FusedPlan:
     fingerprint: str
     segments: Tuple[FusedSegment, ...] = ()
     # optional runtime.fault.FusionFaultInjector: its on_fuse(key) hook
-    # fires just before every fused invocation (the compile-failure seam)
+    # fires just before every fused invocation (the trace-refusal seam)
     injector: Any = None
 
     @property
@@ -332,7 +347,8 @@ def _lower(op: str, attrs: Dict[str, Any], args, fills, vcs,
     ``aux`` is the select mask sum when ``want_aux`` (root selects must
     reproduce the eager engine's ``valid_count`` on the container)."""
     if op == "matmul":
-        return jnp.dot(args[0], args[1]), None, None
+        return jnp.dot(args[0], args[1],
+                       precision=jax.lax.Precision.HIGHEST), None, None
     if op == "add":
         return args[0] + args[1], None, None
     if op == "scale":

@@ -348,7 +348,7 @@ class BigDAWG:
         # callables.  Safe to flip at runtime (the FusedPlan rides the cache
         # entry; fuse=False simply stops passing it to the executor).
         # fusion_injector (runtime.fault.FusionFaultInjector) is the
-        # compile-failure seam for the fallback fault tests
+        # trace-refusal seam for the fallback fault tests
         self.fuse = fuse
         self.fusion_injector = fusion_injector
         # incremental view maintenance (core.deltaplan): warm serves whose
@@ -1019,19 +1019,12 @@ class BigDAWG:
             # recomputing costs): the gate picks the full path
             self.metrics.counter("bd.ivm_fallbacks")
             return None
-        try:
-            res = execute_plan(up.fragment, fplan, tmp, concurrent=True,
-                               cost_model=self.cost_model,
-                               health=self.health, trace=span)
-            merged = deltaplan.apply_update(up, view.value, res.value)
-        except EngineDown:
-            raise    # the failover driver owns breaker-feeding and retries
-        except Exception as exc:
-            warnings.warn(f"incremental update for {sig!r} failed ({exc}); "
-                          f"dropping the view and recomputing")
-            entry.view = None
-            self.metrics.counter("bd.ivm_fallbacks")
-            return None
+        # an engine or kernel failure of the update propagates (EngineDown
+        # to the failover driver): recomputing in full would hide it
+        res = execute_plan(up.fragment, fplan, tmp, concurrent=True,
+                           cost_model=self.cost_model,
+                           health=self.health, trace=span)
+        merged = deltaplan.apply_update(up, view.value, res.value)
         with self._cache_lock:
             view.value = merged
             view.refs = stamps

@@ -15,6 +15,11 @@ Design points:
   fresh interpreter that builds its own middleware from a picklable spec.
   The master never initializes the backend at all — its merge/gather path is
   numpy-only (``tables.concat_shards``/``sum_shards``/``kmerge_shards``).
+* **one chip per worker.**  On a TPU host worker ``i`` is pinned to chip
+  ``i`` before its first JAX call (``device.pin_to_chip``), and a pool with
+  more workers than chips is refused.  A master that has itself started a
+  backend on a chip is refused too (``device.check_pool_placement``): its
+  workers could not open the chip it holds.
 * **pickle-framed pipe RPC.**  One duplex ``multiprocessing.Pipe`` per
   worker; messages are ``(kind, rid, *payload)`` tuples and replies are
   ``("ok"|"err", rid, payload)``.  Replies are matched on ``rid`` — a stale
@@ -139,6 +144,11 @@ def _worker_main(widx: int, conn, spec: Dict[str, Any]) -> None:
     ``reload_shared()`` runs before every execute so plans trained by
     siblings are served warm here.  A training serve persists immediately —
     that is the publication step of the cross-process warm path."""
+    # the chip and the compile cache are chosen before the first JAX call
+    from repro.core import device
+    if spec.get("chips"):
+        device.pin_to_chip(widx)
+    device.enable_compile_cache()
     # deferred so the spawn bootstrap stays import-light until we commit
     from repro.core.middleware import BigDAWG
     from repro.core.monitor import Monitor
@@ -185,6 +195,8 @@ def _worker_main(widx: int, conn, spec: Dict[str, Any]) -> None:
                 conn.send(("ok", rid, None))
             elif kind == "ping":
                 conn.send(("ok", rid, os.getpid()))
+            elif kind == "device":
+                conn.send(("ok", rid, device.describe()))
             elif kind == "stop":
                 conn.send(("ok", rid, None))
                 break
@@ -387,11 +399,14 @@ class ProcPool:
             raise ValueError(f"scatter must be auto|always|never, "
                              f"got {scatter!r}")
         import multiprocessing
+        from repro.core import device
+        # raises before any worker starts if they could not get a chip each
+        chips = device.check_pool_placement(processes)
         self._ctx = multiprocessing.get_context("spawn")
         self.n = processes
         self.state_path = state_path
         self._spec = {"state_path": state_path, "resilient": resilient,
-                      "bigdawg_kwargs": dict(bigdawg_kwargs)}
+                      "chips": chips, "bigdawg_kwargs": dict(bigdawg_kwargs)}
         # tracing: the master mirrors the workers' trace= knob (it rides
         # bigdawg_kwargs into each worker's BigDAWG).  With it on, execute()
         # roots a master-side request span and ships (trace_id, span_id)
@@ -695,6 +710,13 @@ class ProcPool:
                 self._respawn(idx, h)
                 out.append(None)
         return out
+
+    def devices(self) -> List[Dict[str, Any]]:
+        """Each worker's JAX device and the chip it was pinned to
+        (``device.describe``), in worker order."""
+        return [self._rpc(self.workers[idx], "device",
+                          timeout=self.start_timeout_s)
+                for idx in range(self.n)]
 
     def execute(self, query: PolyOp, mode: str = "auto", *,
                 degrade: bool = False,

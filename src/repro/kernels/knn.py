@@ -1,9 +1,14 @@
 """Pallas TPU kernel: kNN score matrix (test @ trainᵀ) as a tiled MXU matmul.
 
-Tiling: (block_b × block_v) @ (block_v × block_n) with the contraction as the
-innermost grid axis and an f32 VMEM accumulator block; all matmul dims are
-kept at multiples of 128 to map onto the 128×128 MXU.  Top-k runs outside the
-kernel (it is O(B·N) and bandwidth-trivial next to the GEMM).
+Tiling: (block_b × V) @ (V × block_n) per grid step.  The contraction runs
+over the whole feature width V in one block (the analytic's V is 224; up to
+``_MAX_V`` fits VMEM), so no partial block ever enters a sum.  ``block_n``
+is a multiple of 128 (lane-dense output tiles), or all of N when N is
+smaller; ``block_b`` is a multiple of 8 or all of B, so one test row
+(B = 1) is a legal block.  N and B that the blocks do not divide get
+partial last blocks: their out-of-range rows and columns are never written
+back.  Top-k runs outside the kernel (it is O(B·N) and bandwidth-trivial
+next to the GEMM).
 """
 from __future__ import annotations
 
@@ -13,40 +18,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _matmul_kernel(t_ref, x_ref, o_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-    o_ref[...] += jnp.dot(t_ref[...], x_ref[...].T,
-                          preferred_element_type=jnp.float32)
+_MAX_V = 2048                 # widest feature axis held in one block
 
 
-def _pick(block, dim):
-    b = min(block, dim)
-    while dim % b:
-        b //= 2
-    return max(b, 1)
+def _scores_kernel(t_ref, x_ref, o_ref):
+    o_ref[...] = jax.lax.dot_general(
+        t_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+
+def _block(block: int, dim: int, align: int) -> int:
+    """``block`` rounded down to ``align``, or all of ``dim`` if that is
+    no larger."""
+    b = max(align, block - block % align)
+    return dim if dim <= b else b
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_b", "block_n", "block_v", "interpret"))
+                   static_argnames=("block_b", "block_n", "interpret"))
 def knn_scores_pallas(train: jnp.ndarray, test: jnp.ndarray,
-                      block_b: int = 128, block_n: int = 256,
-                      block_v: int = 512, interpret: bool = False):
+                      block_b: int = 128, block_n: int = 512,
+                      interpret: bool = False):
     """train: (N, V); test: (B, V) -> scores (B, N) f32."""
     n, v = train.shape
     b = test.shape[0]
-    bb, bn, bv = _pick(block_b, b), _pick(block_n, n), _pick(block_v, v)
-    grid = (b // bb, n // bn, v // bv)
+    if v > _MAX_V:
+        raise ValueError(f"knn_scores_pallas: V={v} > {_MAX_V} does not fit "
+                         f"one VMEM block")
+    bb, bn = _block(block_b, b, 8), _block(block_n, n, 128)
     return pl.pallas_call(
-        _matmul_kernel,
-        grid=grid,
+        _scores_kernel,
+        grid=(pl.cdiv(b, bb), pl.cdiv(n, bn)),
         in_specs=[
-            pl.BlockSpec((bb, bv), lambda i, j, k: (i, k)),   # test block
-            pl.BlockSpec((bn, bv), lambda i, j, k: (j, k)),   # train block
+            pl.BlockSpec((bb, v), lambda i, j: (i, 0)),   # test block
+            pl.BlockSpec((bn, v), lambda i, j: (j, 0)),   # train block
         ],
-        out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         interpret=interpret,
     )(test, train)

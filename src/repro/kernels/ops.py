@@ -1,14 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU backends the Pallas kernels run compiled; elsewhere
-(this CPU container) the pure-jnp references execute, and the kernels
-themselves are validated against those references in interpret mode by
-tests/test_kernels.py.  Set REPRO_FORCE_PALLAS=interpret to route these
-wrappers through the interpret-mode kernels (slow; used by the kernel tests).
+Dispatch policy: on a TPU backend the Pallas kernels always run compiled;
+on any other backend (the CPU test container) the pure-jnp references in
+``kernels/ref.py`` execute.  The kernels themselves are validated against
+those references in interpret mode (``interpret=True``) by
+tests/test_kernels.py, and compiled for a described TPU by
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,45 +19,38 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_scan import ssd_intra_pallas
 
 
-def _mode() -> str:
-    forced = os.environ.get("REPRO_FORCE_PALLAS", "")
-    if forced:
-        return forced                     # "interpret" | "compiled" | "ref"
-    return "compiled" if jax.default_backend() == "tpu" else "ref"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def haar(x: jnp.ndarray, levels: int) -> jnp.ndarray:
-    m = _mode()
-    if m == "ref":
+    """Haar DWT over the last axis of an array of any rank."""
+    if not _on_tpu():
         return ref.haar_ref(x, levels)
-    return haar_pallas(x, levels, interpret=(m == "interpret"))
+    rows = x.reshape(-1, x.shape[-1])
+    return haar_pallas(rows, levels).reshape(x.shape)
 
 
 def knn(train: jnp.ndarray, test: jnp.ndarray, k: int):
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.knn_ref(train, test, k)
-    return knn_pallas(train, test, k, interpret=(m == "interpret"))
+    return knn_pallas(train, test, k)
 
 
 def knn_scores(train, test):
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.knn_scores_ref(train, test)
-    return knn_scores_pallas(train, test, interpret=(m == "interpret"))
+    return knn_scores_pallas(train, test)
 
 
 def flash_attention(q, k, v, *, causal: bool = True):
     """q, k, v: (BH, S, d)."""
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.flash_attention_ref(q, k, v, causal=causal)
-    return flash_attention_pallas(q, k, v, causal=causal,
-                                  interpret=(m == "interpret"))
+    return flash_attention_pallas(q, k, v, causal=causal)
 
 
 def ssd_intra(x, da, B, C):
-    m = _mode()
-    if m == "ref":
+    if not _on_tpu():
         return ref.ssd_intra_ref(x, da, B, C)
-    return ssd_intra_pallas(x, da, B, C, interpret=(m == "interpret"))
+    return ssd_intra_pallas(x, da, B, C)

@@ -10,22 +10,9 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX exposes ``jax.sharding.AxisType`` and lets ``make_mesh`` take
-    ``axis_types``; older releases (e.g. 0.4.x) have neither, and their
-    default behavior is exactly ``AxisType.Auto`` on every axis.  Request
-    Auto explicitly where the API exists, plain ``make_mesh`` where it
-    doesn't — same semantics either way.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

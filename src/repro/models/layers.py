@@ -274,20 +274,9 @@ def _decode_attend_cp(cfg, q, cache_k, cache_v, pos):
 
 
 def _shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: top-level ``jax.shard_map`` with
-    ``check_vma`` on current releases, ``jax.experimental.shard_map`` with
-    ``check_rep`` on 0.4.x."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:     # top-level shard_map that still takes check_rep
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------------------

@@ -89,14 +89,14 @@ class FusionFaultInjector:
     """Fused-segment fault source for plan-level kernel fusion: plugged into
     ``BigDAWG(fusion_injector=...)``, its ``on_fuse`` hook fires in the
     executor just before every fused-segment invocation — the seam where a
-    real trace/compile failure would surface — so tests can force the
-    fused->unfused fallback MID-SERVE and assert it is sticky per segment
-    signature (``fired`` records each key the injector hit).
+    segment that cannot be traced as one program would surface — so tests
+    can force the fused->unfused fallback MID-SERVE and assert it is sticky
+    per segment signature (``fired`` records each key the injector hit).
 
         inj = FusionFaultInjector()
         bd = BigDAWG(fusion_injector=inj)
         ...
-        inj.arm(1)          # next fused invocation raises SimulatedFailure
+        inj.arm(1)          # next fused invocation raises FusionRefused
 
     The raise lands inside the executor's per-segment fallback guard, so the
     serve completes unfused with identical results and the segment key is
@@ -119,8 +119,9 @@ class FusionFaultInjector:
                 return
             self._fail_next -= 1
             self.fired.append(key)
-        raise SimulatedFailure(
-            f"injected fused-segment compile failure for {key!r}")
+        from repro.core.fuseplan import FusionRefused
+        raise FusionRefused(f"injected fused-segment trace refusal for "
+                            f"{key!r}")
 
 
 class WorkerKillInjector:

@@ -341,8 +341,8 @@ def test_fusion_fallback_is_sticky_and_counted():
     srv.submit(q)                              # training
     r1 = srv.submit(q)                         # warm fused serve
     assert r1.fused_segments and r1.fusion_fallbacks == 0
-    inj.arm(1)                                 # next fused call "fails to
-    r2 = srv.submit(q)                         # compile" mid-serve
+    inj.arm(1)                                 # next fused call "refuses
+    r2 = srv.submit(q)                         # to trace" mid-serve
     assert r2.fusion_fallbacks == 1
     assert r2.fused_segments == ()
     np.testing.assert_allclose(np.asarray(r2.result.data),
@@ -359,6 +359,26 @@ def test_fusion_fallback_is_sticky_and_counted():
     assert len(inj.fired) == 1                 # fused path never re-entered
     assert srv.stats["fusion_fallbacks"] == 1
     assert srv.stats["fused_serves"] == 1
+
+
+def test_fused_kernel_failure_propagates_instead_of_falling_back(
+        monkeypatch):
+    """Only a trace refusal re-runs a segment unfused: a compile, kernel or
+    device failure of the fused call reaches the caller, unmarked."""
+    bd = _middleware()
+    srv = QueryServer(bd)
+    q = _pipeline_query()
+    srv.submit(q)                              # training
+    assert srv.submit(q).fused_segments        # warm fused serve
+
+    def refused_kernel(seg, ext_objs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(fuseplan, "run_fused_segment", refused_kernel)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        srv.submit(q)
+    assert fuseplan.broken_keys() == {}
+    assert srv.stats["fusion_fallbacks"] == 0
 
 
 # ---------------------------------------------------------------------------

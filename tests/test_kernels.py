@@ -226,3 +226,37 @@ def test_ssd_intra_matches_full_ssd():
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_full, np.float32),
                                rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# block tails: row counts the row block does not divide (a partial last
+# block, never written back past the end), the MXU-chunked Haar levels of
+# long rows, and knn's 128-multiple train tiles with a ragged tail and B = 1
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,t,levels,block_rows", [
+    (41, 1024, 6, 8),        # 5 full blocks + 1 row; 2 chunked levels
+    (600, 512, 6, 128),      # the analytic's patient count, 1 chunked level
+    (9, 2048, 3, 8),         # chunked levels only: no dense tail
+])
+def test_haar_partial_last_block(n, t, levels, block_rows):
+    x = jax.random.normal(jax.random.PRNGKey(n), (n, t), jnp.float32)
+    got = haar_pallas(x, levels, block_rows=block_rows, interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.haar_ref(x, levels)),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n,v,b,block_n", [
+    (600, 224, 1, 128),      # the analytic: one test patient, ragged tail
+    (300, 96, 11, 128),      # B not a multiple of 8
+    (200, 2048, 3, 128),     # the widest V one block holds
+])
+def test_knn_scores_partial_tiles(n, v, b, block_n):
+    ks = jax.random.split(jax.random.PRNGKey(v), 2)
+    train = jax.random.normal(ks[0], (n, v), jnp.float32)
+    test = jax.random.normal(ks[1], (b, v), jnp.float32)
+    got = knn_scores_pallas(train, test, block_n=block_n, interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.knn_scores_ref(train, test)),
+                               rtol=1e-4, atol=1e-3)

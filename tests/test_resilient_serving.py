@@ -44,12 +44,13 @@ def _portable_query():
     return array.tfidf(array.haar("waves", levels=2))
 
 
-def _resilient_session(threshold=2, cooldown=5.0, **kw):
+def _resilient_session(threshold=2, cooldown=5.0, train_plans=2, **kw):
     t, clock = _fake_clock()
     inj = EngineFaultInjector()
     health = EngineHealth(failure_threshold=threshold, cooldown_s=cooldown,
                           time_fn=clock, injector=inj)
-    s = connect(health=health, train_plans=2, train_repeats=1, **kw)
+    s = connect(health=health, train_plans=train_plans, train_repeats=1,
+                **kw)
     rng = np.random.default_rng(0)
     s.register("waves", DenseTensor(jnp.asarray(
         rng.normal(size=(16, 64)).astype(np.float32))), "dense_array")
@@ -244,7 +245,12 @@ def test_shed_alias_contract():
 # ---------------------------------------------------------------------------
 
 def test_failover_and_recovery_restore_incumbent():
-    s, health, inj, t = _resilient_session(threshold=2, cooldown=5.0)
+    # one trained plan: the DP's best, dense_array only.  With two, the
+    # incumbent is whichever ran faster on the wall clock, and a
+    # two-engine incumbent (dense_array + kv_sparse) can fail over to a plan
+    # that never touches, so never trips, its second engine
+    s, health, inj, t = _resilient_session(threshold=2, cooldown=5.0,
+                                           train_plans=1)
     q = _portable_query()
     s.execute(q, mode="training")
     r_ok = s.execute(q)
