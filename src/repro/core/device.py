@@ -1,5 +1,6 @@
-"""Where a process's JAX work runs: the persistent compile cache, and one
-TPU chip per pool worker.
+"""Where a process's JAX work runs: the persistent compile cache, one TPU
+chip per pool worker, and the clock anchor that puts the program's spans on
+a device profile's clock.
 
 A TPU chip belongs to one process at a time.  A process that has started a
 JAX backend on a chip holds every chip its runtime opened until it exits,
@@ -11,8 +12,9 @@ on the PCI bus, as JAX itself does before it opens them.
 from __future__ import annotations
 
 import os
+import time
 from pathlib import Path
-from typing import Optional
+from typing import Any, Dict, Optional
 
 import jax
 
@@ -22,6 +24,9 @@ CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 _CHIP_PORT_BASE = 8476        # TPU_PROCESS_PORT of the worker on chip 0
+
+# name of the annotation a profile carries for each traced request
+ANCHOR = "bigdawg.clock_anchor"
 
 
 def enable_compile_cache() -> str:
@@ -95,3 +100,26 @@ def describe() -> dict:
     return {"platform": d.platform, "kind": d.device_kind, "id": d.id,
             "count": len(jax.devices()), "pid": os.getpid(),
             "chip": os.environ.get("TPU_VISIBLE_CHIPS")}
+
+
+def memory_stats() -> Dict[str, Any]:
+    """This process's first device's ``memory_stats()`` (``{}`` where the
+    backend reports none)."""
+    return jax.devices()[0].memory_stats() or {}
+
+
+def clock_anchor(span) -> None:
+    """While a profiler traces this process, write one ``ANCHOR``
+    annotation into the profile between two ``perf_counter`` readings,
+    recorded as a ``clock_anchor`` child of ``span``.  Its ``request`` stat
+    is ``r`` and the span's id: the letter keeps the profiler from reading
+    an id such as ``703e-12`` as a number.  The two clocks differ by one
+    offset, so a reader that pairs the annotation with the record maps
+    every span of every request onto the profile's clock.  Does nothing
+    while no profiler runs."""
+    if not jax.profiler.TraceAnnotation.is_enabled():
+        return
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(ANCHOR, request="r" + span.sid):
+        pass
+    span.static_child("clock_anchor", t0, time.perf_counter() - t0)

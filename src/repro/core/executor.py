@@ -153,6 +153,8 @@ class ExecutionResult:
     # keeps such a serve's wall time out of the plan's measured mean so a
     # one-off compile spike can never trigger a divergence re-plan
     fusion_cold_compiles: int = 0
+    # bytes of numpy inputs handed to engine ops (Migrator.host_inputs)
+    h2d_bytes: float = 0.0
 
 
 def _block(x):
@@ -270,6 +272,8 @@ def _task_pred_seconds(node: PolyOp, engine_name: str, catalog, values,
 
 
 def _gather_args(node: PolyOp, eng, catalog, values, migrator):
+    """The node's inputs on its engine, and the bytes of their numpy leaves
+    that the op hands the device (0 for a scope node, the identity)."""
     args = []
     for inp in node.inputs:
         if isinstance(inp, Ref):
@@ -277,10 +281,24 @@ def _gather_args(node: PolyOp, eng, catalog, values, migrator):
         else:
             obj = values[inp.uid]
         args.append(migrator.to_engine(obj, eng.name))
-    return args
+    h2d = 0 if node.op == SCOPE_OP else migrator.host_inputs(args)
+    return args, h2d
 
 
-def _deliver(query: PolyOp, result):
+def _barrier(outs, trace, level) -> None:
+    """Block on every container in ``outs`` (a level's outputs, or the
+    delivered result), recorded as a ``host_sync`` child of ``trace``: the
+    host waiting for the device."""
+    if trace is None:
+        for out in outs:
+            _block(out)
+        return
+    with trace.child("host_sync", level=level):
+        for out in outs:
+            _block(out)
+
+
+def _deliver(query: PolyOp, result, trace=None):
     """Deliver in the root island's data model (location transparency: the
     caller sees the island model regardless of which engine produced it).
 
@@ -295,7 +313,7 @@ def _deliver(query: PolyOp, result):
     if getattr(result, "kind", want) != want:
         from repro.core import cast as castmod
         result = castmod.cast(result, want)
-        _block(result)
+        _barrier((result,), trace, "deliver")
     return result
 
 
@@ -319,11 +337,11 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
     migrator casts its external inputs onto the segment engine (cast-in),
     the single jitted callable evaluates the whole chain with intermediates
     on device, and the segment's measured seconds are attributed back to
-    member nodes pro-rata by predicted cost (``per_node_seconds`` keeps its
-    meaning for the monitor, drift re-planning and the straggler
-    detectors).  ``health.before_op`` still fires per member op, so
-    fault-injection and breakers see fused serves exactly like unfused
-    ones.  A segment that cannot be traced as one program
+    member nodes pro-rata by predicted cost in ``per_node_seconds`` (which
+    keeps its meaning for the monitor, drift re-planning and the straggler
+    detectors; the trace records the segment as one ``fused_segment``).
+    ``health.before_op`` still fires per member op, so fault-injection and
+    breakers see fused serves exactly like unfused ones.  A segment that cannot be traced as one program
     (``fuseplan.FUSION_REFUSALS``) falls back to node-by-node execution of
     the members inside the same task (sticky per segment signature — see
     ``fuseplan.mark_broken``); every other failure propagates as it would
@@ -332,8 +350,8 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
 
     ``trace`` (a ``core.tracing.Span``, or None) attaches already-measured
     ``engine_op`` / ``fused_segment`` / ``cast`` child spans under the
-    caller's span — no extra clock reads beyond the timings this function
-    takes anyway, and zero work when None."""
+    caller's span, and one timed ``host_sync`` per level barrier — zero
+    work when None."""
     amap = plan.engine_map(query)
     migrator = Migrator(cost_model=cost_model, trace=trace)
     values: Dict[int, Any] = {}
@@ -361,7 +379,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
         try:
             if health is not None:
                 health.before_op(eng.name, node.op)
-            args = _gather_args(node, eng, catalog, values, migrator)
+            args, h2d = _gather_args(node, eng, catalog, values, migrator)
             out = args[0] if node.op == SCOPE_OP \
                 else eng.run(node.op, node.attrs, *args)
         except Exception as exc:
@@ -370,7 +388,8 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
         dt = time.perf_counter() - tn
         per_node[node.uid] = dt
         if trace is not None:
-            trace.static_child("engine_op", dt, op=node.op, engine=eng.name)
+            trace.static_child("engine_op", tn, dt, op=node.op,
+                               engine=eng.name, h2d_bytes=h2d)
         return node.uid, out
 
     def _engine_fail(exc: BaseException, engine: str, op: str):
@@ -413,6 +432,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
                                 catalog[src].obj if kind == "ref"
                                 else values[uid_at[src]], eng.name)
                             for kind, src in seg.ext_sources]
+                        h2d = migrator.host_inputs(ext_objs)
                         out, was_cold = fuseplan.run_fused_segment(
                             seg, ext_objs)
                         fused_ran.append(seg.positions)
@@ -434,14 +454,10 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
             for p, w in zip(seg.positions, seg.weights):
                 per_node[uid_at[p]] = dt * w
             if trace is not None:
-                # one fused_segment span, with per-member engine_op children
-                # carrying the same pro-rata attribution per_node got
-                sid = trace.static_child("fused_segment", dt,
-                                         engine=seg.engine,
-                                         positions=list(seg.positions))
-                for p, w in zip(seg.positions, seg.weights):
-                    trace.trace.static("engine_op", sid, dt * w,
-                                       op=node_at[p].op, engine=seg.engine)
+                trace.static_child("fused_segment", tn, dt,
+                                   engine=seg.engine,
+                                   positions=list(seg.positions),
+                                   h2d_bytes=h2d)
             return uid_at[seg.root_pos], out
 
         def _segment_unfused(seg, eng):
@@ -451,7 +467,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
             out = None
             for p in seg.positions:
                 node = node_at[p]
-                args = _gather_args(node, eng, catalog, values, migrator)
+                args, _ = _gather_args(node, eng, catalog, values, migrator)
                 out = eng.run(node.op, node.attrs, *args)
                 values[node.uid] = out
             return out
@@ -496,7 +512,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
             int(os.environ.get("REPRO_HOST_WORKERS", 0)) or \
             DEFAULT_HOST_WORKERS
         pool = host_pool(workers) if workers > 1 else None
-        for level in unit_levels:
+        for li, level in enumerate(unit_levels):
             outs = []
             use_pool = pool is not None and len(level) > 1
             if use_pool and host_workers is None and cost_model is not None:
@@ -525,8 +541,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
                     uid, out = fut.result()
                     values[uid] = out
                     outs.append(out)
-            for out in outs:
-                _block(out)
+            _barrier(outs, trace, li)
     elif concurrent:
         lvls = topo_levels(query)
         n_levels = len(lvls)
@@ -534,7 +549,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
             int(os.environ.get("REPRO_HOST_WORKERS", 0)) or \
             DEFAULT_HOST_WORKERS
         pool = host_pool(workers) if workers > 1 else None
-        for level in lvls:
+        for li, level in enumerate(lvls):
             outs = []
             use_pool = pool is not None and len(level) > 1
             if use_pool and host_workers is None:
@@ -567,8 +582,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
                     uid, out = fut.result()
                     values[uid] = out
                     outs.append(out)
-            for out in outs:                 # one block per level boundary
-                _block(out)
+            _barrier(outs, trace, li)        # one block per level boundary
     else:
         for node in query.nodes():          # post-order
             eng = ENGINES[amap[node.uid]]
@@ -579,7 +593,8 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
             try:
                 if health is not None:
                     health.before_op(eng.name, node.op)
-                args = _gather_args(node, eng, catalog, values, migrator)
+                args, h2d = _gather_args(node, eng, catalog, values,
+                                         migrator)
                 elems = sum(container_elems(a) for a in args)
                 tn = time.perf_counter()
                 if node.op == SCOPE_OP:
@@ -591,7 +606,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
                     out = args[0]
                 else:
                     out = eng.run(node.op, node.attrs, *args)
-                    _block(out)
+                    _barrier((out,), trace, len(per_node))
                     node_obs.append((eng.name, node.op, elems,
                                      time.perf_counter() - tn))
             except Exception as exc:
@@ -600,11 +615,11 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
             dt = time.perf_counter() - tg
             per_node[node.uid] = dt
             if trace is not None:
-                trace.static_child("engine_op", dt, op=node.op,
-                                   engine=eng.name)
+                trace.static_child("engine_op", tg, dt, op=node.op,
+                                   engine=eng.name, h2d_bytes=h2d)
             values[node.uid] = out
 
-    result = _deliver(query, values[query.uid])
+    result = _deliver(query, values[query.uid], trace)
     total = time.perf_counter() - t0
     # size/shape measurement happens OUTSIDE the timed window: observed_nbytes
     # can touch host memory (columnar validity sum) and must not inflate the
@@ -624,7 +639,7 @@ def execute_plan(query: PolyOp, plan: Plan, catalog,
                            migrator.n_casts, plan, per_node, node_obs,
                            list(migrator.events), n_levels, size_obs,
                            shape_obs, tuple(fused_ran), fallbacks[0],
-                           cold_compiles[0])
+                           cold_compiles[0], migrator.h2d_bytes)
 
 
 def merge_shard_results(merge: str, parts, by: Optional[str] = None):

@@ -69,7 +69,7 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Any, Dict, FrozenSet, List, Optional, Set, Tuple, Union
 
-from repro.core import deltaplan, tables, tracing
+from repro.core import deltaplan, device, tables, tracing
 from repro.core.costmodel import CostModel, default_calibration_path
 from repro.core.engines import ENGINES
 from repro.core.errors import EngineDown, PlanInfeasible
@@ -217,6 +217,9 @@ class Report:
     seconds: float
     cast_bytes: float
     sig: str
+    # bytes of numpy inputs handed to engine ops, which the device copies in
+    # on every serve (ExecutionResult.h2d_bytes)
+    h2d_bytes: float = 0.0
     plans_tried: int = 1
     drifted: bool = False
     cache_hit: bool = False  # plan came from the signature-keyed plan cache
@@ -372,6 +375,9 @@ class BigDAWG:
         # training per signature), different signatures run in parallel
         self._sig_locks: Dict[str, threading.RLock] = {}
         self._sig_locks_guard = threading.Lock()
+        # signature -> plan key of its last traced production serve (the
+        # plan_change event; written under the signature's lock)
+        self._served_plans: Dict[str, str] = {}
         # guards plan_cache dict mutation + CachedPlan alternate rotation
         self._cache_lock = threading.RLock()
         # background exploration bookkeeping: at most one in-flight trial per
@@ -771,7 +777,8 @@ class BigDAWG:
         self.save_plan_cache()
         self._maybe_materialize(query, sig, best.value)
         return Report(best.value, best.plan.key, "training", best.seconds,
-                      best.cast_bytes, sig, plans_tried=len(ranked),
+                      best.cast_bytes, sig, h2d_bytes=best.h2d_bytes,
+                      plans_tried=len(ranked),
                       predicted_s=predicted,
                       per_node_seconds=_pos_seconds(query, best))
 
@@ -1033,7 +1040,8 @@ class BigDAWG:
         self.metrics.counter("bd.serve_seconds", seconds)
         self.metrics.observe("bd.serve_latency", seconds)
         return Report(merged, entry.plan.key, "production", seconds,
-                      res.cast_bytes, sig, cache_hit=True,
+                      res.cast_bytes, sig, h2d_bytes=res.h2d_bytes,
+                      cache_hit=True,
                       predicted_s=entry.predicted_s, incremental=True)
 
     def _production(self, query: PolyOp, sig: str,
@@ -1070,7 +1078,9 @@ class BigDAWG:
                 # comparison
                 plan, plan_key, hit = entry.plan, entry.plan.key, True
                 entry.pinned = False
+                replanned_serve = True
             else:
+                prior, replanned_serve = entry, False
                 hit = entry is not None and entry.plan.key == plan_key
                 if hit:
                     plan = entry.plan
@@ -1127,6 +1137,18 @@ class BigDAWG:
             pspan.end()
             span.event("cache_hit" if hit else "cache_miss",
                        plan_key=plan_key)
+            before = self._served_plans.get(sig)
+            if before is not None and before != plan_key:
+                # a plan other than the last one served won: the pinned
+                # result of a re-plan, an explored alternate, or the trained
+                # plans re-ranked on their monitor means
+                reason = "replan" if replanned_serve else "explore" \
+                    if self.explore_budget > 0.0 and prior is not None \
+                    and any(p.key == plan_key for p in prior.alternates) \
+                    else "rerank"
+                span.event("plan_change", **{"from": before, "to": plan_key,
+                                             "reason": reason})
+            self._served_plans[sig] = plan_key
         if self.incremental:
             ispan = span.child("ivm_patch", sig=sig) if span is not None \
                 else None
@@ -1165,7 +1187,8 @@ class BigDAWG:
         self._maybe_materialize(query, sig, res.value)
         explored_key = self._maybe_explore(query, sig, usage)
         return Report(res.value, plan_key, "production", res.seconds,
-                      res.cast_bytes, sig, cache_hit=hit, replanned=replanned,
+                      res.cast_bytes, sig, h2d_bytes=res.h2d_bytes,
+                      cache_hit=hit, replanned=replanned,
                       predicted_s=entry.predicted_s,
                       explored=bool(explored_key), explored_key=explored_key,
                       per_node_seconds=_pos_seconds(query, res),
@@ -1337,8 +1360,8 @@ class BigDAWG:
         self.metrics.counter("bd.serve_seconds", res.seconds)
         self.metrics.observe("bd.serve_latency", res.seconds)
         return Report(res.value, entry.plan.key, "production", res.seconds,
-                      res.cast_bytes, sig, cache_hit=hit,
-                      predicted_s=entry.predicted_s,
+                      res.cast_bytes, sig, h2d_bytes=res.h2d_bytes,
+                      cache_hit=hit, predicted_s=entry.predicted_s,
                       per_node_seconds=_pos_seconds(query, res),
                       fused_segments=res.fused_segments,
                       fusion_fallbacks=res.fusion_fallbacks)
@@ -1446,19 +1469,31 @@ class BigDAWG:
         With tracing on (``BigDAWG(trace=True)``), or when an upstream
         process propagated a ``trace_ctx`` ``(trace_id, parent_span_id)``
         across the pipe RPC, the request records a span tree returned on
-        ``Report.trace`` — a root ``request`` span over plan / train /
-        cast / engine_op / ivm_patch / failover children."""
+        ``Report.trace`` — a root ``request`` span over sig_lock_wait /
+        plan / plan_change / train / cast / engine_op / fused_segment /
+        host_sync / ivm_patch / failover children, and a ``clock_anchor``
+        while a profiler runs (``device.clock_anchor``)."""
         sig = signature(query, self.catalog)
         trace = self.tracer.start(trace_ctx)
         span = trace.root("request", sig=sig, mode=mode) \
             if trace is not None else None
         try:
-            with self._sig_lock(sig):
+            wait = None
+            if span is not None:
+                device.clock_anchor(span)
+                wait = span.child("sig_lock_wait")
+            lock = self._sig_lock(sig)
+            lock.acquire()
+            if wait is not None:
+                wait.end()
+            try:
                 if self.health is not None:
                     rep = self._execute_resilient(query, sig, mode, degrade,
                                                   span=span)
                 else:
                     rep = self._dispatch(query, sig, mode, span=span)
+            finally:
+                lock.release()
         finally:
             if span is not None:
                 span.end()

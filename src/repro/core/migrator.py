@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 from typing import Any, List, Optional, Tuple
 
 from repro.core import cast as castmod
+from repro.core import tables
 from repro.core.engines import ENGINES
 
 
@@ -33,6 +34,8 @@ from repro.core.engines import ENGINES
 class Migrator:
     bytes_moved: float = 0.0
     n_casts: int = 0
+    # bytes of numpy leaves in the inputs handed to engine ops (host_inputs)
+    h2d_bytes: float = 0.0
     # (src_kind, dst_kind, bytes, seconds) per executed cast hop
     events: List[Tuple[str, str, float, float]] = field(default_factory=list)
     cost_model: Optional[Any] = None     # enables calibrated multi-hop routes
@@ -56,12 +59,24 @@ class Migrator:
                 self.n_casts += 1
                 self.events.append((src_kind, dst_kind, float(nbytes), dt))
             if self.trace is not None:     # Trace appends take their own lock
-                self.trace.static_child("cast", dt, src=src_kind,
+                self.trace.static_child("cast", t0, dt, src=src_kind,
                                         dst=dst_kind, bytes=float(nbytes))
         return obj
+
+    def host_inputs(self, args) -> int:
+        """Count the numpy bytes of one op's inputs, which the engine's
+        first device call copies to the device (counted, not synced)."""
+        n = 0
+        for a in args:
+            n += tables.host_nbytes(a)
+        if n:
+            with self._lock:
+                self.h2d_bytes += n
+        return n
 
     def reset(self):
         with self._lock:
             self.bytes_moved = 0.0
             self.n_casts = 0
+            self.h2d_bytes = 0.0
             self.events.clear()

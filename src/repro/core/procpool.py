@@ -60,6 +60,11 @@ Design points:
   patchable), in the master's catalog, and in the respawn replay log — a
   replacement worker replays the CURRENT rows, never a pre-append state.
 
+* **profiled from inside.**  The master never starts a backend, so it
+  cannot trace a chip: ``profile(dir)`` has every worker run
+  ``jax.profiler`` on its own chip, and ``memory_stats()`` returns each
+  worker's device memory counters.
+
 ``ProcPool`` duck-types the middleware surface the serving stack consumes —
 ``execute(query, mode, degrade=)`` returning a ``Report``, ``register``,
 ``persist``, ``health``, ``breaker_trips``, ``catalog`` — so
@@ -68,6 +73,7 @@ without touching the admission logic.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import pickle
@@ -150,6 +156,7 @@ def _worker_main(widx: int, conn, spec: Dict[str, Any]) -> None:
         device.pin_to_chip(widx)
     device.enable_compile_cache()
     # deferred so the spawn bootstrap stays import-light until we commit
+    import jax
     from repro.core.middleware import BigDAWG
     from repro.core.monitor import Monitor
 
@@ -197,6 +204,16 @@ def _worker_main(widx: int, conn, spec: Dict[str, Any]) -> None:
                 conn.send(("ok", rid, os.getpid()))
             elif kind == "device":
                 conn.send(("ok", rid, device.describe()))
+            elif kind == "memory":
+                conn.send(("ok", rid, device.memory_stats()))
+            elif kind == "profile_start":
+                # this worker's chip, into a directory of its own; traced
+                # requests anchor their spans in it (device.clock_anchor)
+                jax.profiler.start_trace(msg[2])
+                conn.send(("ok", rid, None))
+            elif kind == "profile_stop":
+                jax.profiler.stop_trace()
+                conn.send(("ok", rid, None))
             elif kind == "stop":
                 conn.send(("ok", rid, None))
                 break
@@ -318,7 +335,7 @@ class IncrementalGather:
         else:
             t0 = time.perf_counter()
             out = fn()
-            self.span.static_child("gather_fold",
+            self.span.static_child("gather_fold", t0,
                                    time.perf_counter() - t0,
                                    shard=shard, merge=self.merge)
         self.folds += 1
@@ -718,6 +735,33 @@ class ProcPool:
                           timeout=self.start_timeout_s)
                 for idx in range(self.n)]
 
+    def memory_stats(self) -> List[Dict[str, Any]]:
+        """Each worker's device ``memory_stats()`` (``{}`` where the backend
+        reports none), in worker order."""
+        return [self._rpc(self.workers[idx], "memory",
+                          timeout=self.start_timeout_s)
+                for idx in range(self.n)]
+
+    @contextlib.contextmanager
+    def profile(self, log_dir: str):
+        """Run ``jax.profiler`` in every worker while the block runs, each
+        on its own chip: worker ``i`` writes its trace under
+        ``<log_dir>/worker<i>``.  Yields those directories.  A worker starts
+        and stops between two requests (its pipe lock orders the RPCs), and
+        the master stays off JAX."""
+        dirs = [os.path.join(log_dir, f"worker{i}") for i in range(self.n)]
+        started = []
+        try:
+            for idx in range(self.n):
+                self._rpc(self.workers[idx], "profile_start", dirs[idx],
+                          timeout=self.start_timeout_s)
+                started.append(idx)
+            yield dirs
+        finally:
+            for idx in started:
+                self._rpc(self.workers[idx], "profile_stop",
+                          timeout=self.start_timeout_s)
+
     def execute(self, query: PolyOp, mode: str = "auto", *,
                 degrade: bool = False,
                 trace_ctx: Optional[Tuple[str, Optional[str]]] = None):
@@ -820,7 +864,7 @@ class ProcPool:
                     span.trace.adopt(rep.trace)
                 metas[i] = (rep.cast_bytes, rep.mode, rep.cache_hit,
                             getattr(rep, "failovers", 0),
-                            getattr(rep, "degraded", False))
+                            getattr(rep, "degraded", False), rep.h2d_bytes)
                 if i == 0:
                     first_rep[0] = replace(rep, result=None, trace=None)
                 gather.add(i, rep.result)     # frees the frame once folded
@@ -847,6 +891,7 @@ class ProcPool:
             first, result=merged,
             seconds=time.perf_counter() - t0,
             cast_bytes=float(sum(m[0] for m in metas)),
+            h2d_bytes=float(sum(m[5] for m in metas)),
             mode="training" if any(m[1] == "training" for m in metas)
             else "production",
             cache_hit=all(m[2] for m in metas),

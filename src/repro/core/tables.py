@@ -149,6 +149,16 @@ def device_ready(obj):
     return obj
 
 
+def host_nbytes(obj) -> int:
+    """Bytes of a container's numpy (host-resident) array leaves: what a
+    device op has to copy to the device before it can run."""
+    if isinstance(obj, ColumnarTable):
+        leaves = [*obj.columns.values(), obj.valid]
+    else:
+        leaves = getattr(obj, "__dict__", {}).values()
+    return sum(x.nbytes for x in leaves if isinstance(x, np.ndarray))
+
+
 def host_copy(obj):
     """Numpy-leafed clone of a container — what the procpool master pickles
     over the worker pipe (device arrays must not cross a process boundary,

@@ -5,10 +5,11 @@ information about each query"; ``Monitor`` (monitor.py) keeps the
 *aggregate* half of that story (per-signature engine rates that feed the
 optimizer).  This module adds the *request-scoped* half: a ``Tracer``
 produces one ``Trace`` per request — a tree of timed ``Span`` records
-(``plan``, ``cache_hit``/``cache_miss``, ``train``, ``cast``,
-``engine_op``, ``fused_segment``, ``ivm_patch``, ``failover``,
-``queue_wait``, ``worker_dispatch``, ...) with ids, parent ids, and
-attributes (signature, engine, plan key, bytes).
+(``sig_lock_wait``, ``plan``, ``cache_hit``/``cache_miss``,
+``plan_change``, ``train``, ``cast``, ``engine_op``, ``fused_segment``,
+``host_sync``, ``ivm_patch``, ``failover``, ``queue_wait``,
+``worker_dispatch``, ``clock_anchor``, ...) with ids, parent ids, start
+times and attributes (signature, engine, plan key, bytes).
 
 Design constraints, in order:
 
@@ -25,6 +26,12 @@ Design constraints, in order:
    under one lock; the tree is only materialized on demand
    (:meth:`Trace.tree`).  Hot paths that already measured a duration
    attach it via :meth:`Span.static_child` instead of re-timing.
+4. **One clock.**  Every record carries its ``start`` and ``seconds`` on
+   ``time.perf_counter()``, which on Linux is ``CLOCK_MONOTONIC``: one
+   clock for every process on the host, so a worker's adopted records
+   line up with the master's.  A device profile is put on the same clock
+   by ``device.clock_anchor``; this module itself never imports JAX (a
+   pool's master stays off it).
 """
 from __future__ import annotations
 
@@ -68,7 +75,8 @@ class Span:
             return
         self._done = True
         dt = time.perf_counter() - self.t0 if seconds is None else seconds
-        self.trace._append(self.name, self.sid, self.parent, dt, self.attrs)
+        self.trace._append(self.name, self.sid, self.parent, self.t0, dt,
+                           self.attrs)
 
     def __enter__(self) -> "Span":
         return self
@@ -81,14 +89,17 @@ class Span:
         """Start a timed child span."""
         return Span(self.trace, name, self.sid, attrs)
 
-    def static_child(self, name: str, seconds: float, **attrs: Any) -> str:
-        """Record an already-measured child span; returns its span id so
-        further static children can nest under it (pro-rata attribution)."""
-        return self.trace._append(name, _new_id(), self.sid, seconds, attrs)
+    def static_child(self, name: str, start: float, seconds: float,
+                     **attrs: Any) -> str:
+        """Record an already-measured child span (``start`` on
+        ``perf_counter``); returns its span id."""
+        return self.trace._append(name, _new_id(), self.sid, start, seconds,
+                                  attrs)
 
     def event(self, name: str, **attrs: Any) -> str:
         """Record a zero-duration child marker (e.g. ``cache_hit``)."""
-        return self.trace._append(name, _new_id(), self.sid, 0.0, attrs)
+        return self.trace._append(name, _new_id(), self.sid,
+                                  time.perf_counter(), 0.0, attrs)
 
     def annotate(self, **attrs: Any) -> None:
         self.attrs.update(attrs)
@@ -109,22 +120,15 @@ class Trace:
 
     # -- recording ---------------------------------------------------------
     def _append(self, name: str, sid: str, parent: Optional[str],
-                seconds: float, attrs: Dict[str, Any]) -> str:
+                start: float, seconds: float, attrs: Dict[str, Any]) -> str:
         rec = {"name": name, "sid": sid,
                "parent": parent if parent is not None else self.parent_sid,
-               "seconds": float(seconds)}
+               "start": float(start), "seconds": float(seconds)}
         if attrs:
             rec["attrs"] = attrs
         with self._lock:
             self.spans.append(rec)
         return sid
-
-    def static(self, name: str, parent: Optional[str], seconds: float,
-               **attrs: Any) -> str:
-        """Record an already-measured span under an arbitrary parent sid
-        (e.g. per-member ``engine_op`` records nested under a
-        ``fused_segment``'s id)."""
-        return self._append(name, _new_id(), parent, seconds, attrs)
 
     def root(self, name: str, **attrs: Any) -> Span:
         """Start this trace's root span (parented across the process
@@ -156,7 +160,8 @@ class Trace:
 
     def tree(self) -> List[Dict[str, Any]]:
         """Materialize the nested tree: a list of root nodes, each
-        ``{name, sid, seconds, attrs, children: [...]}`` in record order.
+        ``{name, sid, start, seconds, attrs, children: [...]}`` in record
+        order.
         Spans whose parent is unknown (e.g. a worker-side fragment whose
         master span was elided) surface as roots rather than vanishing."""
         with self._lock:

@@ -8,8 +8,12 @@ The cross-island fixtures mirror test_multi_island_api's canonical query
 cache_hit, ivm_patch, engine_op, cast — and over a pool, queue_wait /
 worker_dispatch / a worker-rooted request re-attached under the master's
 tree."""
+import glob
 import multiprocessing
 import os
+import sys
+import threading
+import time
 
 import numpy as np
 import jax.numpy as jnp
@@ -17,10 +21,11 @@ import pytest
 
 from repro.core import (BigDAWG, ColumnarTable, DenseTensor, Span, Trace,
                         Tracer, connect)
-from repro.core import tracing
+from repro.core import device, tracing
 from repro.core.costmodel import CostModel
-from repro.core.executor import DISPATCH_PROBE_WORKERS, _dispatch_overhead
-from repro.core.islands import array
+from repro.core.executor import (DISPATCH_PROBE_WORKERS, _dispatch_overhead,
+                                 execute_plan)
+from repro.core.islands import array, relational
 from repro.core.procpool import ProcPool
 from repro.runtime.fault import WorkerKillInjector
 from repro.runtime.server import QueryServer
@@ -71,15 +76,16 @@ def test_span_tree_basics():
         with root.child("plan") as p:
             p.annotate(plan_key="k")
         root.event("cache_hit", plan_key="k")
-        sid = root.static_child("fused_segment", 0.5, engine="dense_array")
-        t.static("engine_op", sid, 0.25, op="matmul")
+        with root.child("fused_segment", engine="dense_array") as seg:
+            seg.static_child("cast", seg.t0, 0.25, src="columnar")
     tree = t.tree()
     assert len(tree) == 1 and tree[0]["name"] == "request"
     names = [c["name"] for c in tree[0]["children"]]
     assert names == ["plan", "cache_hit", "fused_segment"]
     seg = tree[0]["children"][2]
-    assert seg["children"][0]["name"] == "engine_op"
+    assert seg["children"][0]["name"] == "cast"
     assert seg["children"][0]["seconds"] == 0.25
+    assert seg["children"][0]["start"] == seg["start"]
     assert t.find("cache_hit")[0]["seconds"] == 0.0
     # ids embed the pid -> unique across processes
     assert all(sp["sid"].startswith("%x-" % os.getpid()) for sp in t.spans)
@@ -155,6 +161,158 @@ def test_training_trace_nests_engine_ops_under_train(traced_session):
     assert len(train) == 1 and train[0]["attrs"]["plans"] >= 1
     tsid = train[0]["sid"]
     assert all(sp["parent"] == tsid for sp in t.find("engine_op"))
+
+
+def _assert_nested(spans, slack=1e-6):
+    """Every record has a start, and each child lies within its parent's
+    [start, start + seconds] (one clock for every process)."""
+    by_sid = {sp["sid"]: sp for sp in spans}
+    for sp in spans:
+        assert isinstance(sp["start"], float) and sp["seconds"] >= 0.0
+        parent = by_sid.get(sp["parent"])
+        if parent is None:
+            continue
+        assert parent["start"] - slack <= sp["start"], (parent, sp)
+        assert sp["start"] + sp["seconds"] <= \
+            parent["start"] + parent["seconds"] + slack, (parent, sp)
+
+
+@pytest.mark.parametrize("mode", ["training", "production"])
+def test_every_record_has_a_start_within_its_parent(traced_session, mode):
+    res = traced_session.execute(TEXT_Q, mode=mode)
+    spans = res.trace.spans
+    assert {"request", "sig_lock_wait", "engine_op", "host_sync"} <= \
+        {sp["name"] for sp in spans}
+    _assert_nested(spans)
+
+
+def test_sig_lock_wait_spans_a_held_signature_lock(traced_session):
+    """A second serve of a signature whose lock another serve holds
+    records the wait as ``sig_lock_wait``, a child of its request."""
+    bd = traced_session.bigdawg
+    q = traced_session.parse(TEXT_Q)
+    lock = bd._sig_lock(traced_session.execute(q).sig)
+    out = {}
+    with lock:
+        t = threading.Thread(target=lambda: out.update(r=bd.execute(q)))
+        t.start()
+        time.sleep(1.0)
+        released = time.perf_counter()
+    t.join(60)
+    spans = out["r"].trace.spans
+    root = [sp for sp in spans if sp["name"] == "request"][0]
+    wait = [sp for sp in spans if sp["name"] == "sig_lock_wait"]
+    assert len(wait) == 1 and wait[0]["parent"] == root["sid"]
+    # it began while the lock was held and ended once it was released
+    assert wait[0]["start"] < released <= wait[0]["start"] + wait[0]["seconds"]
+
+
+@pytest.mark.parametrize("concurrent", [True, False],
+                         ids=["levels", "sequential"])
+def test_host_sync_once_per_barrier(traced_session, concurrent):
+    """Concurrent dispatch blocks once per topological level, sequential
+    dispatch once per engine op: each barrier is one ``host_sync``."""
+    bd = traced_session.bigdawg
+    q = traced_session.parse(TEXT_Q)
+    plan = bd.plan_cache[traced_session.execute(q).sig].plan
+    t = Tracer(True).start()
+    with t.root("request") as root:
+        res = execute_plan(q, plan, bd.catalog, concurrent=concurrent,
+                           trace=root)
+    syncs = t.find("host_sync")
+    assert all(sp["parent"] == root.sid for sp in syncs)
+    levels = [sp["attrs"]["level"] for sp in syncs]
+    if concurrent:
+        assert levels == list(range(res.levels))
+    else:
+        ops = [sp for sp in t.find("engine_op")
+               if sp["attrs"]["op"] != "scope"]
+        assert len(syncs) == len(ops) >= 2
+
+
+def test_plan_change_event_on_a_forced_replan():
+    """The serve after a re-plan runs the new plan: its trace carries a
+    ``plan_change`` event from the old plan to the new, reason ``replan``.
+    Serves on an unchanged plan carry none.  One trained plan, and no
+    re-plan until the test forces one: timing noise cannot move it."""
+    bd = BigDAWG(train_plans=1, replan_factor=float("inf"), trace=True)
+    rng = np.random.default_rng(0)
+    bd.register("waves", DenseTensor(jnp.asarray(
+        rng.normal(size=(32, 64)).astype(np.float32))), engine="dense_array")
+    q = array.tfidf(array.haar(
+        relational.select("waves", column="value", lo=0.5), levels=2))
+    rep = bd.execute(q, mode="training")
+    for _ in range(2):
+        assert not bd.execute(q, mode="production").trace.find("plan_change")
+    bd.replan_factor = BigDAWG.REPLAN_FACTOR
+    entry = bd.plan_cache[rep.sig]
+    entry.pinned = entry.restored = False
+    entry.predicted_s *= 50.0
+    # the engine the trained plan did not use becomes the cheap one
+    slow = "columnar" if "columnar" in rep.plan_key else "dense_array"
+    fast = "dense_array" if slow == "columnar" else "columnar"
+    for op in ("select", "haar", "tfidf"):
+        bd.cost_model.observe_op(fast, op, 1e6, 1e-4)
+        bd.cost_model.observe_op(slow, op, 1e6, 10.0)
+    rep2 = bd.execute(q, mode="production")
+    assert rep2.replanned and not rep2.trace.find("plan_change")
+    rep3 = bd.execute(q, mode="production")
+    assert rep3.plan_key != rep2.plan_key
+    changes = rep3.trace.find("plan_change")
+    assert [c["attrs"] for c in changes] == [
+        {"from": rep2.plan_key, "to": rep3.plan_key, "reason": "replan"}]
+
+
+@pytest.mark.parametrize("numpy_home", [True, False],
+                         ids=["numpy", "device"])
+def test_h2d_bytes_counts_numpy_inputs(numpy_home):
+    """A table registered as numpy is copied to the device by every op that
+    reads it: ``Report.h2d_bytes`` and the ``engine_op`` span count its
+    bytes; a device-resident table counts 0."""
+    x = np.random.default_rng(1).normal(size=(16, 8)).astype(np.float32)
+    bd = BigDAWG(train_plans=1, trace=True)
+    bd.register("X", DenseTensor(x if numpy_home else jnp.asarray(x)),
+                "dense_array")
+    q = array.scale("X", factor=2.0)
+    bd.execute(q, mode="training")
+    rep = bd.execute(q, mode="production")
+    want = x.nbytes if numpy_home else 0
+    assert rep.h2d_bytes == want
+    assert [sp["attrs"]["h2d_bytes"] for sp in rep.trace.find("engine_op")
+            ] == [want]
+
+
+def test_disabled_tracer_reads_no_clock(monkeypatch, traced_session):
+    """With tracing off a warm serve reads the clock only for its own
+    measurements: from the same places, as often as a traced serve of the
+    same plan does, and never in the tracer."""
+    bd = traced_session.bigdawg
+    q = traced_session.parse(TEXT_Q)
+    bd.execute(q)
+    real = time.perf_counter
+    calls = {}
+
+    def counting():
+        code = sys._getframe(1).f_code
+        key = (os.path.basename(code.co_filename), code.co_name)
+        calls[key] = calls.get(key, 0) + 1
+        return real()
+
+    def count(enabled):
+        calls.clear()
+        bd.tracer.enabled = enabled
+        try:
+            monkeypatch.setattr(time, "perf_counter", counting)
+            bd.execute(q)
+        finally:
+            monkeypatch.setattr(time, "perf_counter", real)
+            bd.tracer.enabled = True
+        return dict(calls)
+
+    traced, untraced = count(True), count(False)
+    assert any(k[0] == "tracing.py" for k in traced)
+    assert untraced == {k: n for k, n in traced.items()
+                        if k[0] != "tracing.py"}
 
 
 def test_trace_off_by_default_and_zero_alloc(monkeypatch):
@@ -435,6 +593,35 @@ def test_pool_scatter_trace_collects_all_shards():
         assert p.metrics.value("pool.scatter_serves") >= 1
     finally:
         p.close()
+
+
+def test_pool_profiles_and_memory_from_inside_its_workers(traced_pool,
+                                                         tmp_path):
+    """``ProcPool.profile`` has each worker trace its own device into its
+    own directory; a traced request served meanwhile carries the worker's
+    ``clock_anchor``, whose annotation is in that worker's profile; and
+    ``memory_stats`` answers for every worker."""
+    from jax.profiler import ProfileData
+    q = array.matmul("M", "W")
+    traced_pool.execute(q, mode="training")
+    with traced_pool.profile(str(tmp_path)) as dirs:
+        rep = traced_pool.execute(q)
+    assert dirs == [str(tmp_path / f"worker{i}") for i in range(2)]
+    anchors = rep.trace.find("clock_anchor")
+    assert len(anchors) == 1
+    pid = anchors[0]["sid"].split("-")[0]
+    found = []
+    for d in dirs:
+        for f in glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                           recursive=True):
+            for plane in ProfileData.from_file(f).planes:
+                for line in plane.lines:
+                    found += [dict(e.stats).get("request")
+                              for e in line.events if e.name == device.ANCHOR]
+    assert found == ["r" + anchors[0]["parent"]]
+    assert pid != "%x" % os.getpid()
+    stats = traced_pool.memory_stats()
+    assert len(stats) == 2 and all(isinstance(s, dict) for s in stats)
 
 
 def test_pool_metrics_persist_merges_workers(traced_pool):
