@@ -26,6 +26,7 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.core.tables import COOMatrix, ColumnarTable, DenseTensor, StreamBuffer
 
@@ -105,21 +106,36 @@ def _da_haar(attrs, d: DenseTensor):
     return DenseTensor(kops.haar(d.data, attrs["levels"]))
 
 
+@partial(jax.jit, static_argnames=("nbins", "levels"))
+def dense_bin_hist(x, lo, span, scale, *, nbins: int, levels: int):
+    """(N, T) Haar coefficients -> (N, (levels + 1) * nbins) float32 counts,
+    band by band; the bin of ``x`` is ``clip(int32((x - lo) / span * scale),
+    0, nbins - 1)``.  Each band's bins are compared with every bin and
+    summed in one program, which XLA fuses into the sum: no (N, band, nbins)
+    buffer is stored.  ``lo``, ``span`` and ``scale`` are operands, as they
+    are to the op-by-op formula: a constant divisor would let XLA multiply
+    by its reciprocal instead, which rounds differently at some bin edges.
+
+    Every device op of the program carries the frontend attribute
+    ``bigdawg_op="dense_bin_hist"``: a TPU trace names an op by its HLO
+    text, and the attribute is what finds these ops there."""
+    with set_xla_metadata(bigdawg_op="dense_bin_hist"):
+        outs = []
+        for off, ln in _scale_slices(x.shape[1], levels):
+            idx = jnp.clip(((x[:, off:off + ln] - lo) / span * scale)
+                           .astype(jnp.int32), 0, nbins - 1)
+            outs.append(jnp.sum(idx[:, :, None] == jnp.arange(nbins), axis=1,
+                                dtype=jnp.float32))
+        return jnp.concatenate(outs, axis=1)
+
+
 def _da_bin_hist(attrs, d: DenseTensor):
-    """Per-scale histograms of Haar coefficients via one-hot matmul — the
-    dense engine pays for scatter-free layout with a padded one-hot GEMM."""
+    """Per-band histograms of Haar coefficients, one compiled call: the
+    coefficients are read once and no one-hot is materialised."""
     nbins, levels = attrs["nbins"], attrs["levels"]
     lo, hi = attrs.get("lo", -3.0), attrs.get("hi", 3.0)
-    N, T = d.data.shape
-    slices = _scale_slices(T, levels)
-    outs = []
-    for off, ln in slices:
-        seg = d.data[:, off:off + ln]
-        idx = jnp.clip(((seg - lo) / (hi - lo) * nbins).astype(jnp.int32),
-                       0, nbins - 1)
-        oh = jax.nn.one_hot(idx, nbins, dtype=jnp.float32)   # (N, ln, nbins)
-        outs.append(jnp.einsum("nlb->nb", oh))
-    return DenseTensor(jnp.concatenate(outs, axis=1))
+    return DenseTensor(dense_bin_hist(d.data, lo, hi - lo, nbins,
+                                      nbins=nbins, levels=levels))
 
 
 def _da_tfidf(attrs, d: DenseTensor):

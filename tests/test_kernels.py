@@ -260,3 +260,112 @@ def test_knn_scores_partial_tiles(n, v, b, block_n):
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref.knn_scores_ref(train, test)),
                                rtol=1e-4, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the dense engine's per-band histograms (core/engines.py dense_bin_hist), one
+# compiled program a call, against numpy's count of the same bin formula and
+# against that formula run op by op as a one-hot and its sum, exactly, with
+# values planted at every bin edge and a few ulps either side of it, at lo
+# and hi, and outside them
+# ---------------------------------------------------------------------------
+
+def _np_bin_hist(x, nbins, levels, lo, hi):
+    from repro.core.engines import _scale_slices
+    with np.errstate(over="ignore", invalid="ignore"):
+        u = (x - np.float32(lo)) / np.float32(hi - lo) * np.float32(nbins)
+    idx = np.clip(np.trunc(u), 0, nbins - 1).astype(np.int64)
+    return np.concatenate(
+        [np.stack([(idx[:, off:off + ln] == b).sum(1) for b in range(nbins)],
+                  1) for off, ln in _scale_slices(x.shape[1], levels)],
+        1).astype(np.float32)
+
+
+def _op_by_op_bin_hist(x, nbins, levels, lo, hi):
+    """The dense engine's bin_hist as eager ops, band by band."""
+    from repro.core.engines import _scale_slices
+    outs = []
+    for off, ln in _scale_slices(x.shape[1], levels):
+        idx = jnp.clip(((x[:, off:off + ln] - lo) / (hi - lo) * nbins)
+                       .astype(jnp.int32), 0, nbins - 1)
+        outs.append(jnp.einsum("nlb->nb", jax.nn.one_hot(idx, nbins,
+                                                         dtype=jnp.float32)))
+    return jnp.concatenate(outs, axis=1)
+
+
+def _planted(n, t, nbins, lo, hi, seed):
+    rng = np.random.default_rng(seed)
+    x = (rng.standard_normal((n, t)) * (hi - lo) / 3).astype(np.float32)
+    edges = np.float32(lo) + np.float32((hi - lo) / nbins) * np.arange(
+        nbins + 1, dtype=np.float32)
+    near = [edges]
+    for way in (np.float32(-np.inf), np.float32(np.inf)):
+        v = edges
+        for _ in range(4):
+            v = np.nextafter(v, way)
+            near.append(v)
+    plant = np.concatenate(near + [np.float32(
+        [lo, hi, lo - 1, hi + 1, 1e30, -1e30, np.inf, -np.inf])])
+    pos = rng.choice(x.size, size=min(x.size, 4 * plant.size), replace=False)
+    x.ravel()[pos] = np.resize(plant, pos.size)
+    return x
+
+
+def _dense_bin_hist(x, **attrs):
+    from repro.core.engines import ENGINES
+    from repro.core.tables import DenseTensor
+    return np.asarray(ENGINES["dense_array"].run(
+        "bin_hist", attrs, DenseTensor(jnp.asarray(x))).data)
+
+
+@pytest.mark.parametrize("n,t,levels,nbins,lo,hi", [
+    (41, 16384, 6, 32, -3.0, 3.0),   # the analytic's width; 41 = 32 + 9 rows
+    (600, 1024, 6, 32, -3.0, 3.0),   # 600 rows
+    (128, 128, 2, 8, -3.0, 3.0),     # the cost model's calibration probe
+    (64, 1024, 4, 16, -3.0, 3.0),    # tests/test_system.py: 64-wide bands
+    (13, 256, 3, 7, -1.3, 2.9),      # other lo/hi, odd bin count
+    (20, 192, 6, 32, -3.0, 3.0),     # T not a multiple of 128: 3-wide bands
+    (5, 64, 2, 4, -0.5, 0.25),       # fewer rows than a sublane group
+])
+def test_bin_hist(n, t, levels, nbins, lo, hi):
+    x = _planted(n, t, nbins, lo, hi, seed=n * t)
+    want = _np_bin_hist(x, nbins, levels, lo, hi)
+    got = _dense_bin_hist(x, nbins=nbins, levels=levels, lo=lo, hi=hi)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(_op_by_op_bin_hist(jnp.asarray(x), nbins, levels, lo, hi)),
+        want)
+    assert want.sum(1).tolist() == [t] * n
+
+
+def test_bin_hist_defaults_and_one_program():
+    """Without lo/hi the op bins over [-3, 3), and a call is one dispatch:
+    its jaxpr is a single ``jit`` of ``dense_bin_hist``."""
+    from repro.core.engines import _da_bin_hist
+    from repro.core.tables import DenseTensor
+    x = _planted(16, 256, 32, -3.0, 3.0, seed=3)
+    np.testing.assert_array_equal(_dense_bin_hist(x, nbins=32, levels=3),
+                                  _np_bin_hist(x, 32, 3, -3.0, 3.0))
+    jaxpr = jax.make_jaxpr(lambda v: _da_bin_hist(
+        {"nbins": 32, "levels": 3}, DenseTensor(v)).data)(jnp.asarray(x))
+    assert [e.primitive.name for e in jaxpr.eqns] == ["jit"]
+    assert jaxpr.eqns[0].params["name"] == "dense_bin_hist"
+
+
+def test_dense_bin_hist_equals_columnar():
+    """The dense engine's histograms equal the columnar engine's
+    sort/segment ones on the same coefficients."""
+    from repro.core.cast import dense_to_columnar
+    from repro.core.engines import ENGINES
+    from repro.core.tables import DenseTensor
+    x = _planted(48, 1024, 32, -3.0, 3.0, seed=7)
+    # the columnar engine's numpy cast to int32 wraps what lies beyond it
+    x = np.clip(x, -100.0, 100.0)
+    attrs = {"nbins": 32, "levels": 6}
+    d = DenseTensor(jnp.asarray(x))
+    dense = np.asarray(ENGINES["dense_array"].run("bin_hist", attrs, d).data)
+    col = ENGINES["columnar"].run("bin_hist", attrs, dense_to_columnar(d))
+    i, j = np.asarray(col.columns["i"]), np.asarray(col.columns["j"])
+    got = np.zeros_like(dense)
+    got[i, j] = np.asarray(col.columns["value"])
+    np.testing.assert_array_equal(dense, got)
